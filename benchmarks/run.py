@@ -100,6 +100,9 @@ def main(argv: list[str] | None = None) -> int:
         print("--height and --width must be given together", file=sys.stderr)
         return 2
 
+    from repro.launch.compile_cache import place_compile_cache
+
+    place_compile_cache()
     # Resolve the device-aware dispatch once, so the CSV header and the
     # JSON meta state which backend/tile/gather this run actually used.
     from repro.kernels.registry import get_backend, resolve_dispatch

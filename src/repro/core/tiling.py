@@ -79,8 +79,9 @@ GATHER_IMPLS = WINDOWED_GATHERS + ("stream",)
 #: ``"int8"``
 #:     the low-precision datapath: descriptors stay int8 and the SAD
 #:     accumulates in int16 (exact -- the 16-sample SAD is bounded by
-#:     16 * 255 = 4080 < 2^15) before the float32 energy.  Narrower
-#:     vectors per lane on TPU; bitwise identical outputs by construction.
+#:     16 * 255 = 4080 < 2^15) before the float32 energy.  Bitwise
+#:     identical outputs by construction.  The XLA scan honours it; the
+#:     Pallas kernels accumulate in int32 for either value.
 PRECISION_IMPLS = ("f32", "int8")
 
 #: Explicit "run the untiled path" request, now that ``tile=None`` resolves

@@ -19,6 +19,7 @@ import threading
 from typing import Optional, Tuple
 
 import jax
+from jax._src.mesh import thread_resources
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -121,20 +122,13 @@ def logical_to_spec(
 
 
 def _current_mesh() -> Optional[Mesh]:
-    # jax >= 0.5 exposes the ambient mesh as jax.sharding.get_abstract_mesh;
-    # older releases only have the thread-local resource env.  Support both.
-    get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract_mesh is not None:
-        env_mesh = get_abstract_mesh()
-        if env_mesh is not None and env_mesh.shape_tuple:
-            return env_mesh
-    try:
-        from jax._src.mesh import thread_resources
-
-        m = thread_resources.env.physical_mesh
-        return m if not m.empty else None
-    except Exception:
-        return None
+    # ``jax.set_mesh`` sets the abstract mesh; ``with mesh:`` only the
+    # thread-local resource env.
+    env_mesh = jax.sharding.get_abstract_mesh()
+    if env_mesh.shape_tuple:
+        return env_mesh
+    m = thread_resources.env.physical_mesh
+    return None if m.empty else m
 
 
 def logical_constraint(

@@ -148,10 +148,11 @@ def _pallas_backend(name: str, interpret: bool, description: str) -> KernelBacke
         support_match_tiled=support_tiled,
         dense_match_stream=dense_stream,
         tiling=TileCapability(
-            tiled_dense=True, default_rows=4, max_rows=64,
-            tiled_support=True, support_default_rows=4, support_max_rows=64,
-            default_gather="stream",   # slices/compares only: Mosaic-ready
-            default_precision="int8",  # narrow SAD datapath (exact; bitwise)
+            # 8-row blocks: the f32 sublane tile (Mosaic refuses 4-row maps)
+            tiled_dense=True, default_rows=8, max_rows=64,
+            tiled_support=True, support_default_rows=8, support_max_rows=64,
+            default_gather="stream",   # the kernel Mosaic lowers
+            default_precision="int8",  # the kernels accumulate int32 either way
         ),
         description=description,
     )

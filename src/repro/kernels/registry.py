@@ -127,9 +127,11 @@ def default_backend() -> str:
        registered name -- the operational escape hatch (e.g. force
        ``pallas`` to run the kernel bodies in interpret mode on CPU, or
        pin ``ref`` on a TPU host while debugging a Mosaic lowering).
-    2. ``jax.default_backend() == "tpu"`` -> ``pallas_tpu``: the Pallas
-       kernels compiled by Mosaic, with the one-hot-matmul candidate
-       gather their capability declares as ``default_gather``.
+    2. ``jax.default_backend() == "tpu"`` -> ``pallas_tpu``: the support
+       and streaming dense kernels compiled by Mosaic (the gather-free
+       ``"stream"`` scan their capability declares as ``default_gather``).
+       Nothing falls back at run time: a kernel that fails to compile
+       fails the call.
     3. Anything else (``cpu``, ``gpu``) -> ``ref``: the pure-jnp
        streaming-scan formulation, which XLA compiles natively everywhere
        (interpret-mode Pallas is a correctness harness, never a

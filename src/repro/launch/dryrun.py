@@ -88,21 +88,8 @@ def collective_bytes(hlo_text: str) -> dict:
 
 
 def peak_memory_bytes(mem) -> int:
-    """Peak device memory from a ``CompiledMemoryStats``, across jax versions.
-
-    Newer jaxlibs report ``peak_memory_in_bytes`` directly; older ones only
-    expose the per-category sizes, whose sum bounds the peak (arguments,
-    outputs and temps are all live at some point during the computation).
-    """
-    peak = getattr(mem, "peak_memory_in_bytes", None)
-    if peak:
-        return int(peak)
-    return int(
-        getattr(mem, "argument_size_in_bytes", 0)
-        + getattr(mem, "output_size_in_bytes", 0)
-        + getattr(mem, "temp_size_in_bytes", 0)
-        - getattr(mem, "alias_size_in_bytes", 0)
-    )
+    """Peak device memory from a ``CompiledMemoryStats``."""
+    return int(mem.peak_memory_in_bytes)
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
 
 from repro.distributed.sharding import ShardingRules
 from repro.models.config import ModelConfig
@@ -19,7 +20,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     composes with data for DP/FSDP and carries the slow inter-pod links."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _axis_size(mesh, name: str) -> int:

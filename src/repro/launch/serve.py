@@ -8,6 +8,7 @@
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import jax
@@ -18,6 +19,7 @@ from repro.configs.elas_stereo import SYNTH
 from repro.data.stereo import synthetic_stereo_pair
 from repro.models.model import LMModel
 from repro.serving.engine import ServeEngine
+from repro.launch.compile_cache import place_compile_cache
 from repro.serving.stereo_service import StereoService
 
 
@@ -61,17 +63,23 @@ def serve_stereo(args) -> int:
     t0 = time.monotonic()
     for i, (l, r) in enumerate(frames):
         svc.submit(i, l, r)
-    results = svc.results(args.frames, timeout=600.0)
+    done = svc.collect(args.frames, timeout=600.0)
     wall = time.monotonic() - t0
     st = svc.stats()
     svc.stop()
-    fps = len(results) / wall
-    print(f"{args.frames} frames in {wall:.2f}s -> {fps:.1f} fps "
-          f"({args.height}x{args.width}, batch={args.batch}, CPU backend)")
+    ok = sum(c.ok for c in done)
+    dev = jax.devices()[0]
+    print(f"{ok}/{args.frames} frames ok in {wall:.2f}s -> "
+          f"{len(done) / wall:.1f} fps ({args.height}x{args.width}, "
+          f"batch={args.batch}, platform={dev.platform}, "
+          f"device_kind={dev.device_kind}, backend={st.backend})")
     print(f"waves={st.waves} occupancy={st.wave_occupancy:.2f} "
           f"cache={st.cache_hits}h/{st.cache_misses}m "
           f"p95={st.latency_p95_ms:.0f}ms")
-    return 0
+    for c in done:
+        if not c.ok:
+            print(f"frame {c.frame_id} failed: {c.error}", file=sys.stderr)
+    return 0 if ok == args.frames else 1
 
 
 def main(argv=None) -> int:
@@ -93,6 +101,7 @@ def main(argv=None) -> int:
     st.add_argument("--width", type=int, default=160)
 
     args = ap.parse_args(argv)
+    place_compile_cache()
     return serve_lm(args) if args.mode == "lm" else serve_stereo(args)
 
 

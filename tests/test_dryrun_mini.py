@@ -52,7 +52,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.distributed.sharding import logical_to_spec, use_rules
@@ -60,7 +60,7 @@ from repro.launch.mesh import make_rules
 from repro.launch.dryrun import _shardings_for, collective_bytes, peak_memory_bytes
 from repro.models.model import LMModel, cache_specs
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 cfg = get_config("gemma2-27b", reduced=True)
 rules = make_rules(cfg, mesh, global_batch=4)
 model = LMModel(cfg)
