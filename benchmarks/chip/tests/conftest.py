@@ -1,0 +1,9 @@
+"""The benchmark's own tests: run from the repository root with
+``python -m pytest benchmarks/chip/tests`` (CPU, small shapes)."""
+import sys
+from pathlib import Path
+
+_ROOT = Path(__file__).resolve().parents[3]
+for p in (str(_ROOT / "src"), str(_ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
