@@ -21,6 +21,7 @@ from repro.models.model import LMModel
 from repro.serving.engine import ServeEngine
 from repro.launch.compile_cache import place_compile_cache
 from repro.serving.stereo_service import StereoService
+from repro.serving.tracing import PART_KIND
 
 
 def serve_lm(args) -> int:
@@ -75,7 +76,13 @@ def serve_stereo(args) -> int:
           f"device_kind={dev.device_kind}, backend={st.backend})")
     print(f"waves={st.waves} occupancy={st.wave_occupancy:.2f} "
           f"cache={st.cache_hits}h/{st.cache_misses}m "
-          f"p95={st.latency_p95_ms:.0f}ms")
+          f"p95={st.latency_p95_ms:.0f}ms "
+          f"compiles_after_warmup={st.compiles_after_warmup}")
+    parts = [c.timing.parts() for c in done if c.timing is not None]
+    if parts:
+        print("latency parts, mean ms: " + " ".join(
+            f"{k}={sum(p[k] for p in parts) / len(parts) * 1e3:.2f}"
+            for k in PART_KIND))
     for c in done:
         if not c.ok:
             print(f"frame {c.frame_id} failed: {c.error}", file=sys.stderr)

@@ -8,8 +8,8 @@ Three mechanisms (each unit-tested with injected failures):
   evict/replace nodes before they stall the collective.  The same monitor
   doubles as stage-thread liveness for the stereo serving engine
   (:mod:`repro.serving.stereo_service`): each stage loop beats once per
-  poll with its wave count as the step, so a wedged stage shows up as
-  dead and a slow one as a straggler in ``StereoService.stats()``.
+  queue poll, so a wedged stage shows up as dead in
+  ``StereoService.stats()``.
 * ``run_with_recovery`` -- wraps the train loop: on failure, restores the
   latest checkpoint and replays.  Batches are a pure function of step
   (repro.data.tokens), so recovery is bitwise-deterministic.

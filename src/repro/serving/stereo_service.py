@@ -42,9 +42,15 @@ generalisation of both ideas for many concurrent streams:
   boundary as the paper's Fig. 3 subsystems.
 
 * **Accounting** -- per-request latency, wave occupancy, backpressure time
-  spent blocked in ``submit()``, program-cache counters, admission /
-  containment counters and per-stage liveness, snapshotted by
-  :meth:`StereoService.stats`.
+  spent blocked in ``submit()``, program-cache counters, compiles on the
+  serving path, admission / containment counters and per-stage liveness,
+  snapshotted by :meth:`StereoService.stats`.
+
+* **Tracing** (:mod:`repro.serving.tracing`) -- every unit of work runs in
+  a ``stereo.*`` span: a profiler annotation carrying the wave index plus
+  ``time.monotonic()`` stamps.  The stamps become each delivered frame's
+  :class:`~repro.serving.tracing.FrameTiming`, whose parts (host work,
+  program runs, waits) sum to its ``latency_s``.
 
 The split wave programs produce *bitwise identical* output to the fused
 single-frame :func:`~repro.core.pipeline.ielas_disparity` program (pinned by
@@ -86,10 +92,10 @@ containment rules (proved by ``tests/test_serving_faults.py`` via the
   conformance is pinned against exactly that path.
 
 * **Liveness** -- every stage thread beats a
-  :class:`~repro.runtime.fault_tolerance.HeartbeatMonitor` once per poll
-  (step = waves processed), so ``stats()`` reports per-stage liveness and
-  stragglers, and ``stop(drain=True)`` detects a dead/aborted pipeline
-  promptly instead of sleeping out its timeout.
+  :class:`~repro.runtime.fault_tolerance.HeartbeatMonitor` once per queue
+  poll, so ``stats()`` reports per-stage liveness, and
+  ``stop(drain=True)`` detects a dead/aborted pipeline promptly instead of
+  sleeping out its timeout.
 
 * **Temporal warm-start** (``warm_start=True``; proved by
   tests/test_warm_start.py and the warm cases of the faults suite) --
@@ -174,6 +180,7 @@ from repro.kernels.registry import resolve_dispatch
 from repro.runtime.fault_tolerance import HeartbeatMonitor
 from repro.serving.admission import AdmissionController
 from repro.serving.faults import FaultPlan
+from repro.serving.tracing import CompileCounter, FrameTiming, span
 from repro.serving.warmstart import (
     WarmState,
     frame_thumbnail,
@@ -205,6 +212,7 @@ class CompletedFrame:
     disparity: Optional[np.ndarray]    # (H, W) float32, native resolution
     latency_s: float                   # submit() -> emitted
     error: Optional[str] = None        # terminal failure reason, if any
+    timing: Optional[FrameTiming] = None   # where latency_s went (ok frames)
 
     @property
     def ok(self) -> bool:
@@ -246,7 +254,9 @@ class ServiceStats:
     admitted_by_stream: tuple = () # ((stream_id, admitted), ...) fairness view
     shed_by_stream: tuple = ()     # ((stream_id, shed), ...)
     stage_liveness: tuple = ()     # ((stage, alive), ...) from the heartbeat
-    stage_stragglers: tuple = ()   # stage names slower than the median
+    compiles_after_warmup: int = 0  # XLA compiles (cache loads included) run
+                                    # by the stage threads, not by warmup()
+    compiles_by_stage: tuple = ()  # ((stage, compiles), ...) of the above
     # ---- temporal warm-start (PR 10; all zero with warm_start=False) ----
     warm_frames: int = 0           # frames classified warm (band-only scan)
     cold_frames: int = 0           # warm-start frames classified cold
@@ -544,6 +554,9 @@ class _Request:
     h: int
     w: int
     t_submit: float
+    t_enqueued: float = 0.0    # handed to the ingest put
+    t_finished: float = 0.0    # reached _finish (in_order hold starts)
+    stamps: Optional[dict] = None      # its wave's _Wave.t, set at emit
     seq: int = 0               # per-stream submission sequence (in_order
                                # reordering AND warm-start chain identity)
     deadline: Optional[float] = None   # absolute time.monotonic() budget
@@ -566,6 +579,8 @@ class _Wave:
     programs: Optional[WavePrograms] = None
     mid: Optional[tuple] = None    # (dl, dr, support) between stages
     disp: object = None
+    t: dict = dataclasses.field(default_factory=dict)   # its FrameTiming
+                                   # fields: wave index, build/program/emit
 
 
 # ---------------------------------------------------------------------------
@@ -733,7 +748,7 @@ class StereoService:
         self._monitor = HeartbeatMonitor(
             hosts=list(_STAGES), timeout=heartbeat_timeout, clock=clock
         )
-        self._stage_steps: dict = {s: 0 for s in _STAGES}
+        self._compiles = CompileCounter()
 
         # Warm-start lock: guards the per-stream WarmState map and the warm
         # counters.  Touched by assembly (classification), emit (post-hoc
@@ -798,7 +813,6 @@ class StereoService:
             hosts=list(_STAGES), timeout=self.heartbeat_timeout,
             clock=self._clock,
         )
-        self._stage_steps = {s: 0 for s in _STAGES}
         for q in (self._waves, self._mid, self._ready):
             while True:
                 try:
@@ -846,15 +860,11 @@ class StereoService:
                 0, self._submitted - self._completed - self._failed
                 - self._shed - self._ingest.qsize()
             )
-        stages = [
-            ("stereo-assemble", self._assemble_loop),
-            ("stereo-support", self._support_loop),
-            ("stereo-dense", self._dense_loop),
-            ("stereo-emit", self._emit_loop),
-        ]
-        for name, target in stages:
-            t = threading.Thread(target=self._guard(target), name=name,
-                                 daemon=True)
+        loops = (self._assemble_loop, self._support_loop, self._dense_loop,
+                  self._emit_loop)
+        for stage, target in zip(_STAGES, loops):
+            t = threading.Thread(target=self._guard(target, stage),
+                                 name=f"stereo-{stage}", daemon=True)
             t.start()
             self._threads.append(t)
         return self
@@ -903,8 +913,9 @@ class StereoService:
             if exc_type is None:    # don't mask the exception already in flight
                 raise
 
-    def _guard(self, target):
+    def _guard(self, target, stage: str):
         def run():
+            self._compiles.bind(stage)
             try:
                 target()
             except BaseException as e:            # noqa: BLE001
@@ -948,61 +959,64 @@ class StereoService:
         Blocks only when ``max_pending`` requests are already in flight --
         the backpressure point (time spent blocked is accounted in
         :meth:`stats`)."""
-        if self._error is not None:
-            raise RuntimeError("stereo service worker failed") from self._error
-        left = np.asarray(left, np.float32)
-        right = np.asarray(right, np.float32)
-        if left.shape != right.shape or left.ndim != 2:
-            raise ValueError(
-                f"expected matching (H, W) pairs, got {left.shape} vs {right.shape}"
-            )
-        min_dim = max(self.params.grid_size, self.params.candidate_step)
-        if left.shape[0] < min_dim or left.shape[1] < min_dim:
-            raise ValueError(
-                f"frame {left.shape} too small: needs at least one "
-                f"{min_dim}x{min_dim} grid cell (grid_size={self.params.grid_size})"
-            )
-        if deadline is not None:
-            deadline = float(deadline)
-        now = time.monotonic()
-        with self._slock:
-            rid = self._next_request_id
-            self._next_request_id += 1
-            # Sequence numbers exist for the in_order reordering buffer and
-            # for warm-start chain identity (the state machine must prove a
-            # frame's seed is its immediate predecessor); without either,
-            # skip the per-stream dict so a service fed fresh stream ids
-            # per client never accumulates bookkeeping.
-            seq = 0
-            if self.in_order or self.warm_start:
-                seq = self._stream_seq[stream_id]
-                self._stream_seq[stream_id] = seq + 1
-            if self._t_first_submit is None:
-                self._t_first_submit = now
-            self._inflight[rid] = (stream_id, frame_id)
-        req = _Request(
-            request_id=rid, stream_id=stream_id, frame_id=frame_id,
-            left=left, right=right, h=left.shape[0], w=left.shape[1],
-            t_submit=now, seq=seq, deadline=deadline,
-        )
-        t0 = time.monotonic()
-        while True:     # abort-aware put: never deadlock on a dead service
+        with span("stereo.submit", stream=stream_id):
             if self._error is not None:
-                raise RuntimeError(
-                    "stereo service worker failed") from self._error
-            try:
-                self._ingest.put(req, timeout=0.05)
-                break
-            except queue.Full:
-                if not self._threads:
+                raise RuntimeError("stereo service worker failed") from self._error
+            left = np.asarray(left, np.float32)
+            right = np.asarray(right, np.float32)
+            if left.shape != right.shape or left.ndim != 2:
+                raise ValueError(
+                    f"expected matching (H, W) pairs, got {left.shape} vs {right.shape}"
+                )
+            min_dim = max(self.params.grid_size, self.params.candidate_step)
+            if left.shape[0] < min_dim or left.shape[1] < min_dim:
+                raise ValueError(
+                    f"frame {left.shape} too small: needs at least one "
+                    f"{min_dim}x{min_dim} grid cell (grid_size={self.params.grid_size})"
+                )
+            if deadline is not None:
+                deadline = float(deadline)
+            now = time.monotonic()
+            with self._slock:
+                rid = self._next_request_id
+                self._next_request_id += 1
+                # Sequence numbers exist for the in_order reordering buffer and
+                # for warm-start chain identity (the state machine must prove a
+                # frame's seed is its immediate predecessor); without either,
+                # skip the per-stream dict so a service fed fresh stream ids
+                # per client never accumulates bookkeeping.
+                seq = 0
+                if self.in_order or self.warm_start:
+                    seq = self._stream_seq[stream_id]
+                    self._stream_seq[stream_id] = seq + 1
+                if self._t_first_submit is None:
+                    self._t_first_submit = now
+                self._inflight[rid] = (stream_id, frame_id)
+            req = _Request(
+                request_id=rid, stream_id=stream_id, frame_id=frame_id,
+                left=left, right=right, h=left.shape[0], w=left.shape[1],
+                t_submit=now, seq=seq, deadline=deadline,
+            )
+            # Stamped before the put: the assembler may take the request
+            # before put() returns, and a blocked put is a queue wait.
+            req.t_enqueued = time.monotonic()
+            while True:     # abort-aware put: never deadlock on a dead service
+                if self._error is not None:
                     raise RuntimeError(
-                        "ingest queue full and service not running"
-                    ) from None
-        waited = time.monotonic() - t0
-        with self._slock:
-            self._submitted += 1
-            self._backpressure_s += waited
-        return rid
+                        "stereo service worker failed") from self._error
+                try:
+                    self._ingest.put(req, timeout=0.05)
+                    break
+                except queue.Full:
+                    if not self._threads:
+                        raise RuntimeError(
+                            "ingest queue full and service not running"
+                        ) from None
+            waited = time.monotonic() - req.t_enqueued
+            with self._slock:
+                self._submitted += 1
+                self._backpressure_s += waited
+            return rid
 
     def collect(self, n: int, timeout: float = 60.0,
                 strict: bool = False) -> list[CompletedFrame]:
@@ -1088,7 +1102,7 @@ class StereoService:
         liveness = tuple(
             (s, s not in dead) for s in _STAGES
         ) if self._threads else ()
-        stragglers = tuple(self._monitor.stragglers()) if self._threads else ()
+        compiles = self._compiles.snapshot()
         with self._slock:
             lats = sorted(self._latencies)
             n = len(lats)
@@ -1134,7 +1148,8 @@ class StereoService:
                 admitted_by_stream=adm["admitted_by_stream"],
                 shed_by_stream=adm["shed_by_stream"],
                 stage_liveness=liveness,
-                stage_stragglers=stragglers,
+                compiles_after_warmup=sum(n for _, n in compiles),
+                compiles_by_stage=compiles,
                 warm_frames=warm[0],
                 cold_frames=warm[1],
                 scene_changes=warm[2],
@@ -1145,11 +1160,7 @@ class StereoService:
 
     # ------------------------------------------------------- stage plumbing
     def _beat(self, stage: str) -> None:
-        self._monitor.beat(stage, self._stage_steps[stage])
-
-    def _step(self, stage: str) -> None:
-        self._stage_steps[stage] += 1
-        self._monitor.beat(stage, self._stage_steps[stage])
+        self._monitor.beat(stage, 0)    # liveness reads only the beat's time
 
     def _put(self, q: queue.Queue, item, stage: str) -> bool:
         while not self._abort.is_set():
@@ -1212,18 +1223,20 @@ class StereoService:
             warm = pending[0].warm
             width = self._cache.batch_for(*key)
             deadline = time.monotonic() + self.wave_linger
-            while (not draining
-                   and sum(self._cache.bucket_shape(r.h, r.w) == key
-                           and r.warm == warm for r in pending) < width):
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    break
-                try:
-                    req = self._ingest.get(timeout=remaining)
-                    self._classify_warm(req)
-                    pending.append(req)
-                except queue.Empty:
-                    break
+            # Only this thread builds waves: the next one gets this index.
+            with span("stereo.assemble.linger", wave=self._waves_built):
+                while (not draining
+                       and sum(self._cache.bucket_shape(r.h, r.w) == key
+                               and r.warm == warm for r in pending) < width):
+                    remaining = deadline - time.monotonic()
+                    if remaining <= 0:
+                        break
+                    try:
+                        req = self._ingest.get(timeout=remaining)
+                        self._classify_warm(req)
+                        pending.append(req)
+                    except queue.Empty:
+                        break
 
             # Admission: deadline shedding + per-stream round-robin slots
             # over the head bucket's candidates.
@@ -1249,7 +1262,6 @@ class StereoService:
             wave = self._build_wave(key, admitted, width, degraded, warm)
             if not self._put(self._waves, wave, "assemble"):
                 return
-            self._step("assemble")
 
     def _classify_warm(self, req: _Request) -> None:
         """The warm/cold decision for one frame, pinned as it enters
@@ -1329,24 +1341,6 @@ class StereoService:
                 return img
             return np.pad(img, ((0, bh - h), (0, bw - w)), mode="edge")
 
-        lefts = [fit(r.left) for r in reqs]
-        rights = [fit(r.right) for r in reqs]
-        if pad:                     # replicate a real frame into padded slots
-            lefts += [lefts[0]] * pad
-            rights += [rights[0]] * pad
-        prior = None
-        if warm:
-            # Stack the pinned per-frame priors (padded slots replicate a
-            # real one, like the frames above).  Warm requests KEEP their
-            # host frames/priors: the emit stage needs them for the
-            # post-hoc disagreement check and its cold re-run.
-            priors = [fit(r.prior) for r in reqs]
-            if pad:
-                priors += [priors[0]] * pad
-            prior = jnp.asarray(np.stack(priors))
-        else:
-            for r in reqs:          # emit only needs ids/shape/timing: release
-                r.left = r.right = None  # host frames while waves are queued
         with self._slock:
             index = self._waves_built
             self._waves_built += 1
@@ -1354,12 +1348,33 @@ class StereoService:
             self._padded_slots += pad
             if degraded:
                 self._degraded_waves += 1
-        return _Wave(
-            key=key, requests=reqs, index=index, degraded=degraded,
-            warm=warm, prior=prior,
-            left=jnp.asarray(np.stack(lefts)),
-            right=jnp.asarray(np.stack(rights)),
-        )
+        with span("stereo.assemble.build", wave=index) as sp:
+            lefts = [fit(r.left) for r in reqs]
+            rights = [fit(r.right) for r in reqs]
+            if pad:                 # replicate a real frame into padded slots
+                lefts += [lefts[0]] * pad
+                rights += [rights[0]] * pad
+            prior = None
+            if warm:
+                # Stack the pinned per-frame priors (padded slots replicate
+                # a real one, like the frames above).  Warm requests KEEP
+                # their host frames/priors: the emit stage needs them for
+                # the post-hoc disagreement check and its cold re-run.
+                priors = [fit(r.prior) for r in reqs]
+                if pad:
+                    priors += [priors[0]] * pad
+                prior = jnp.asarray(np.stack(priors))
+            else:
+                for r in reqs:      # emit only needs ids/shape/timing: release
+                    r.left = r.right = None  # host frames while waves queue
+            wave = _Wave(
+                key=key, requests=reqs, index=index, degraded=degraded,
+                warm=warm, prior=prior,
+                left=jnp.asarray(np.stack(lefts)),
+                right=jnp.asarray(np.stack(rights)),
+            )
+        wave.t.update(wave=index, build_start=sp.start, build_end=sp.end)
+        return wave
 
     # ------------------------------------------- stages 1+2: contained exec
     def _check_faults(self, stage: str, wave: _Wave) -> None:
@@ -1372,7 +1387,8 @@ class StereoService:
     def _exec_stage(self, wave: _Wave, stage: str) -> None:
         """Run one stage's program over one wave, blocking on the result so
         failures surface HERE -- in the stage that owns the retry -- rather
-        than asynchronously at emit."""
+        than asynchronously at emit.  The stage's span covers dispatch to
+        ready and gives the wave its ``<stage>_dispatch/_ready`` stamps."""
         self._check_faults(stage, wave)
         if stage == "support":
             wave.programs = self._cache.get(
@@ -1380,8 +1396,9 @@ class StereoService:
             )
             support = (wave.programs.support_warm if wave.warm
                        else wave.programs.support)
-            wave.mid = support(wave.left, wave.right)
-            jax.block_until_ready(wave.mid)
+            with span("stereo.support.run", wave=wave.index) as sp:
+                wave.mid = support(wave.left, wave.right)
+                jax.block_until_ready(wave.mid)
             wave.left = wave.right = None
         else:
             prog = wave.programs
@@ -1390,15 +1407,18 @@ class StereoService:
                          if wave.degraded
                          and prog.dense_warm_degraded is not None
                          else prog.dense_warm)
-                wave.disp = dense(*wave.mid, wave.prior)
+                args = (*wave.mid, wave.prior)
             else:
                 dense = (prog.dense_degraded
                          if wave.degraded and prog.dense_degraded is not None
                          else prog.dense)
-                wave.disp = dense(*wave.mid)
-            jax.block_until_ready(wave.disp)
+                args = wave.mid
+            with span("stereo.dense.run", wave=wave.index) as sp:
+                wave.disp = dense(*args)
+                jax.block_until_ready(wave.disp)
             wave.mid = None
             wave.prior = None
+        wave.t[f"{stage}_dispatch"], wave.t[f"{stage}_ready"] = sp.start, sp.end
 
     def _retry_slot(self, wave: _Wave, stage: str, slot: int) -> _Wave:
         """The bounded retry: re-run ONE slot of a failed wave as a
@@ -1412,28 +1432,31 @@ class StereoService:
         prog = self._cache.get(*wave.key, batch=1)
         sub = _Wave(key=wave.key, requests=[req], left=None, right=None,
                     index=wave.index, degraded=wave.degraded, warm=wave.warm,
-                    programs=prog)
+                    programs=prog, t=dict(wave.t))
         if self.fault_plan is not None:
             self.fault_plan.check(stage, wave.index, (req.request_id,))
-        if stage == "support":
-            support = prog.support_warm if wave.warm else prog.support
-            sub.mid = support(wave.left[slot:slot + 1],
-                              wave.right[slot:slot + 1])
-            jax.block_until_ready(sub.mid)
-        else:
-            mid = tuple(m[slot:slot + 1] for m in wave.mid)
-            if wave.warm:
-                dense = (prog.dense_warm_degraded
-                         if wave.degraded
-                         and prog.dense_warm_degraded is not None
-                         else prog.dense_warm)
-                sub.disp = dense(*mid, wave.prior[slot:slot + 1])
+        with span("stereo.retry", wave=wave.index, stage=stage) as sp:
+            if stage == "support":
+                support = prog.support_warm if wave.warm else prog.support
+                sub.mid = support(wave.left[slot:slot + 1],
+                                  wave.right[slot:slot + 1])
+                jax.block_until_ready(sub.mid)
             else:
-                dense = (prog.dense_degraded
-                         if wave.degraded and prog.dense_degraded is not None
-                         else prog.dense)
-                sub.disp = dense(*mid)
-            jax.block_until_ready(sub.disp)
+                mid = tuple(m[slot:slot + 1] for m in wave.mid)
+                if wave.warm:
+                    dense = (prog.dense_warm_degraded
+                             if wave.degraded
+                             and prog.dense_warm_degraded is not None
+                             else prog.dense_warm)
+                    sub.disp = dense(*mid, wave.prior[slot:slot + 1])
+                else:
+                    dense = (prog.dense_degraded
+                             if wave.degraded
+                             and prog.dense_degraded is not None
+                             else prog.dense)
+                    sub.disp = dense(*mid)
+                jax.block_until_ready(sub.disp)
+        sub.t[f"{stage}_dispatch"], sub.t[f"{stage}_ready"] = sp.start, sp.end
         return sub
 
     def _contain(self, wave: _Wave, stage: str, exc: Exception,
@@ -1493,7 +1516,6 @@ class StereoService:
                     self._consec_wave_failures = 0
                 if not self._put(downstream, wave, stage):
                     return
-            self._step(stage)
 
     def _support_loop(self) -> None:
         self._stage_loop("support", self._waves, self._mid)
@@ -1512,7 +1534,8 @@ class StereoService:
                 return
             try:
                 self._check_faults("emit", wave)
-                disp = np.asarray(wave.disp)   # device -> host sync point
+                with span("stereo.emit.readback", wave=wave.index) as sp:
+                    disp = np.asarray(wave.disp)   # device -> host sync
             except Exception as e:             # noqa: BLE001 -- contain: the
                 # wave's device buffers are gone, so there is no retry here;
                 # its frames fail terminally but the engine stays up.
@@ -1529,19 +1552,20 @@ class StereoService:
                         f"systemic failure: {self.max_wave_failures} "
                         f"consecutive waves failed at emit"
                     ) from e
-                self._step("emit")
                 continue
             with self._slock:
                 self._consec_wave_failures = 0
-            for slot, req in enumerate(wave.requests):
-                out = np.ascontiguousarray(disp[slot, : req.h, : req.w])
-                error = None
-                if wave.warm:
-                    out, error = self._posthoc_check(req, out, wave.key)
-                    req.left = req.right = req.prior = None
-                self._finish(req, out, error=error)
+            wave.t.update(emit_start=sp.start, readback_end=sp.end)
+            with span("stereo.emit.deliver", wave=wave.index):
+                for slot, req in enumerate(wave.requests):
+                    out = np.ascontiguousarray(disp[slot, : req.h, : req.w])
+                    error = None
+                    if wave.warm:
+                        out, error = self._posthoc_check(req, out, wave.key)
+                        req.left = req.right = req.prior = None
+                    req.stamps = wave.t
+                    self._finish(req, out, error=error)
             wave.disp = None
-            self._step("emit")
 
     def _posthoc_check(self, req: _Request, out: np.ndarray,
                        key: tuple) -> tuple:
@@ -1556,7 +1580,8 @@ class StereoService:
         with self._wlock:
             self._warm_reruns += 1
         try:
-            return self._run_cold_single(req, key), None
+            with span("stereo.warm.rerun", request=req.request_id):
+                return self._run_cold_single(req, key), None
         except Exception as e:             # noqa: BLE001 -- contained: the
             # re-run failing fails only this frame, like any compute fault
             return None, (
@@ -1588,6 +1613,7 @@ class StereoService:
         or admission shed.  Honors the in_order reordering buffer: every
         terminal state advances the stream's sequence, so a failed or shed
         frame never blocks the frames behind it."""
+        req.t_finished = time.monotonic()
         if not self.in_order:
             self._deliver(req, out, error, shed)
             return
@@ -1654,8 +1680,14 @@ class StereoService:
             else:
                 self._failed += 1
             self._t_last_emit = now
+        timing = None
+        if error is None and req.stamps is not None:
+            timing = FrameTiming(
+                submit=req.t_submit, enqueued=req.t_enqueued, **req.stamps,
+                finished=req.t_finished, delivered=now,
+            )
         self._out.put(CompletedFrame(
             request_id=req.request_id, stream_id=req.stream_id,
             frame_id=req.frame_id, disparity=out, latency_s=lat,
-            error=error,
+            error=error, timing=timing,
         ))

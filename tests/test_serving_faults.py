@@ -459,4 +459,4 @@ class TestFailFast:
     def test_stats_before_start_has_no_liveness(self):
         svc = StereoService(P, batch=1)
         st = svc.stats()
-        assert st.stage_liveness == () and st.stage_stragglers == ()
+        assert st.stage_liveness == ()
