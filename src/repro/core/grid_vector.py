@@ -7,8 +7,8 @@ original stores all 256).  Dense matching then only evaluates these K
 candidates plus the plane-prior neighbourhood.
 
 Because the support nodes sit on a regular lattice whose pitch divides the
-cell size, the pooling is a static strided-window gather -- no histograms,
-no variable-length sets.
+cell size, the pooling is a static reshape and shifted slices -- no
+histograms, no variable-length sets, no gather.
 """
 from __future__ import annotations
 
@@ -45,11 +45,18 @@ def build_grid_vector(support: jax.Array, p: ElasParams) -> jax.Array:
         ((npc, npc), (npc, npc)),
         constant_values=INVALID,
     )
-    patches = []
-    for dy in range(win):
-        for dx in range(win):
-            patches.append(padded[dy : dy + ch * npc : npc, dx : dx + cw * npc : npc])
-    pool = jnp.stack(patches, axis=-1)              # (CH, CW, win*win)
+    # Node (cy*npc + dy, cx*npc + dx) of the padded grid, as a reshape to
+    # (cell, node-in-cell) per axis and three shifted cell slices per axis.
+    def shifts(x, axis, n):       # (.., n, ..) -> (.., n, 3, ..): cells q, q+1, q+2
+        return jnp.stack(
+            [jax.lax.slice_in_dim(x, q, q + n, axis=axis) for q in range(3)],
+            axis=axis + 1,
+        )
+
+    r = padded.reshape(ch + 2, npc, cw + 2, npc)
+    rows = shifts(r, 0, ch).reshape(ch, win, cw + 2, npc)           # (CH, dy, ., .)
+    both = shifts(rows, 2, cw).reshape(ch, win, cw, win)            # (CH, dy, CW, dx)
+    pool = both.transpose(0, 2, 1, 3).reshape(ch, cw, win * win)    # (CH, CW, win*win)
 
     valid = pool != INVALID
     big = jnp.float32(1e9)
@@ -64,7 +71,9 @@ def build_grid_vector(support: jax.Array, p: ElasParams) -> jax.Array:
         jnp.round(ranks * scale / jnp.maximum(k - 1, 1)).astype(jnp.int32),
         0,
     )
-    reps = jnp.take_along_axis(sorted_pool, idx, axis=-1)
+    # Rank selection as a one-hot max over the win*win sorted slots.
+    pick = idx[..., None] == jnp.arange(win * win)
+    reps = jnp.max(jnp.where(pick, sorted_pool[..., None, :], -jnp.inf), axis=-1)
     return jnp.where(n_valid[..., None] > 0, reps, p.const_fill)
 
 

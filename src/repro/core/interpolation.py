@@ -18,9 +18,10 @@ window (right / bottom) is truncated by the image boundary, the leading
 (left / top) value alone is used -- exactly what a streaming line-buffer
 implementation produces at frame edges.
 
-Everything is O(GH*GW) via ``lax.cummax`` nearest-valid-index propagation --
-no data-dependent control flow, no scatter: the "regular manner" the paper
-advertises, expressed in XLA-native form.
+Everything is O(GH*GW*s_delta) static shifted slices and selects over the
+``s_delta`` window -- no data-dependent control flow, no scatter, no
+gather: the "regular manner" the paper advertises, expressed in
+XLA-native form.
 """
 from __future__ import annotations
 
@@ -33,26 +34,31 @@ from repro.core.params import ElasParams
 from repro.core.support import INVALID
 
 
-def _nearest_valid_lr(grid: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Nearest valid value/distance to the left and right along rows.
+def nearest_valid_lr(
+    grid: jax.Array, reach: int
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Nearest valid value/distance to the left and right along rows,
+    looking at most ``reach`` entries away (distance 0 is the entry itself).
 
-    Returns (val_l, dist_l, val_r, dist_r); dist is +inf-like (big) where no
-    valid node exists on that side.
+    Returns (val_l, dist_l, val_r, dist_r); dist is ``reach + 1`` and val
+    INVALID where no valid entry lies within ``reach`` on that side.  The
+    search is a static loop of shifted slices, far to near so the nearest
+    valid entry writes last -- no gather, no data-dependent index.
     """
-    gh, gw = grid.shape
-    valid = grid != INVALID
-    col = jnp.broadcast_to(jnp.arange(gw)[None, :], grid.shape)
-    big = jnp.int32(1 << 30)   # "no valid neighbour" must exceed ANY s_delta
-
-    idx_l = jax.lax.cummax(jnp.where(valid, col, -1), axis=1)
-    val_l = jnp.take_along_axis(grid, jnp.maximum(idx_l, 0), axis=1)
-    dist_l = jnp.where(idx_l >= 0, col - idx_l, big)
-
-    rev = jnp.flip(grid, axis=1)
-    valid_r = rev != INVALID
-    idx_rev = jax.lax.cummax(jnp.where(valid_r, col, -1), axis=1)
-    val_r = jnp.flip(jnp.take_along_axis(rev, jnp.maximum(idx_rev, 0), axis=1), axis=1)
-    dist_r = jnp.flip(jnp.where(idx_rev >= 0, col - idx_rev, big), axis=1)
+    gw = grid.shape[1]
+    reach = max(reach, 0)
+    n = min(reach, gw - 1)
+    padded = jnp.pad(grid, ((0, 0), (n, n)), constant_values=INVALID)
+    none = jnp.full(grid.shape, reach + 1, jnp.int32)
+    val_l = val_r = jnp.full_like(grid, INVALID)
+    dist_l = dist_r = none
+    for s in range(n, -1, -1):
+        left = padded[:, n - s : n - s + gw]
+        right = padded[:, n + s : n + s + gw]
+        val_l = jnp.where(left != INVALID, left, val_l)
+        dist_l = jnp.where(left != INVALID, s, dist_l)
+        val_r = jnp.where(right != INVALID, right, val_r)
+        dist_r = jnp.where(right != INVALID, s, dist_r)
     return val_l, dist_l, val_r, dist_r
 
 
@@ -71,7 +77,7 @@ def _axis_interpolation(
     """One-axis (horizontal) interpolation: returns (value, found_mask)."""
     gw = grid.shape[1]
     col = jnp.arange(gw)[None, :]
-    val_l, dist_l, val_r, dist_r = _nearest_valid_lr(grid)
+    val_l, dist_l, val_r, dist_r = nearest_valid_lr(grid, p.s_delta)
 
     has_l = dist_l <= p.s_delta
     has_r = dist_r <= p.s_delta

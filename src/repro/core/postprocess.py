@@ -1,8 +1,8 @@
 """Post-processing: left/right consistency, gap interpolation, median filter.
 
-All stages are branch-free window/scan ops (the same nearest-valid-neighbour
-machinery as the support interpolation), so the whole post-process chain
-stays on-device.
+All stages are branch-free static shifts and selects (the same
+nearest-valid-neighbour machinery as the support interpolation), so the
+whole post-process chain stays on-device and holds no gather.
 """
 from __future__ import annotations
 
@@ -11,6 +11,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.interpolation import nearest_valid_lr
 from repro.core.params import ElasParams
 
 INVALID = -1.0
@@ -20,11 +21,21 @@ INVALID = -1.0
 def lr_consistency(
     disp_left: jax.Array, disp_right: jax.Array, p: ElasParams
 ) -> jax.Array:
-    """Invalidate pixels whose right-image counterpart disagrees."""
+    """Invalidate pixels whose right-image counterpart disagrees.
+
+    ``disp_left`` must hold a dense scan's output: whole disparities
+    ``d`` in ``[disp_min, disp_max]`` (as float32) or INVALID.  A pixel
+    at column ``u`` reads ``disp_right[:, clip(u - d, 0, W - 1)]``, i.e.
+    the ``d``-th static shift of an edge-padded ``disp_right``, picked by
+    a select over the ``num_disp`` shifts -- no gather.  An INVALID pixel
+    matches no shift and fails the check whatever it would read.
+    """
     h, w = disp_left.shape
-    u = jnp.arange(w, dtype=jnp.float32)[None, :]
-    ur = jnp.clip(u - disp_left, 0, w - 1).astype(jnp.int32)
-    d_r = jnp.take_along_axis(disp_right, ur, axis=1)
+    lead, trail = max(p.disp_max, 0), max(-p.disp_min, 0)
+    padded = jnp.pad(disp_right, ((0, 0), (lead, trail)), mode="edge")
+    d_r = jnp.full_like(disp_right, INVALID)
+    for d in range(p.disp_min, p.disp_max + 1):
+        d_r = jnp.where(disp_left == d, padded[:, lead - d : lead - d + w], d_r)
     ok = (
         (disp_left != INVALID)
         & (d_r != INVALID)
@@ -33,37 +44,19 @@ def lr_consistency(
     return jnp.where(ok, disp_left, INVALID)
 
 
-def _nearest_lr(disp: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    h, w = disp.shape
-    valid = disp != INVALID
-    col = jnp.broadcast_to(jnp.arange(w)[None, :], disp.shape)
-    big = jnp.int32(1 << 30)   # "no valid neighbour" sentinel
-    idx_l = jax.lax.cummax(jnp.where(valid, col, -1), axis=1)
-    val_l = jnp.take_along_axis(disp, jnp.maximum(idx_l, 0), axis=1)
-    dist_l = jnp.where(idx_l >= 0, col - idx_l, big)
-    rev = jnp.flip(disp, axis=1)
-    validr = rev != INVALID
-    idx_r = jax.lax.cummax(jnp.where(validr, col, -1), axis=1)
-    val_r = jnp.flip(jnp.take_along_axis(rev, jnp.maximum(idx_r, 0), axis=1), axis=1)
-    dist_r = jnp.flip(jnp.where(idx_r >= 0, col - idx_r, big), axis=1)
-    return val_l, dist_l, val_r, dist_r
-
-
 @functools.partial(jax.jit, static_argnames=("p",))
 def gap_interpolation(disp: jax.Array, p: ElasParams) -> jax.Array:
     """Fill horizontal invalid runs of length <= ipol_gap_width.
 
     Smooth gaps (end difference <= 5) are filled linearly; discontinuities
     take the min (background wins, occlusion-aware) -- libelas semantics.
+    A gap's ends each lie within ``ipol_gap_width`` of its pixels, so the
+    nearest-valid search looks no further; beyond it the distance reads
+    ``ipol_gap_width + 1`` and the gap is too wide to fill.
     """
-    val_l, dist_l, val_r, dist_r = _nearest_lr(disp)
+    val_l, dist_l, val_r, dist_r = nearest_valid_lr(disp, p.ipol_gap_width)
     gap = dist_l + dist_r - 1
-    fillable = (
-        (disp == INVALID)
-        & (dist_l < disp.shape[1] + 1)
-        & (dist_r < disp.shape[1] + 1)
-        & (gap <= p.ipol_gap_width)
-    )
+    fillable = (disp == INVALID) & (gap <= p.ipol_gap_width)
     t = dist_l.astype(jnp.float32) / jnp.maximum(dist_l + dist_r, 1).astype(jnp.float32)
     linear = val_l + t * (val_r - val_l)
     fill = jnp.where(jnp.abs(val_l - val_r) <= 5.0, linear, jnp.minimum(val_l, val_r))

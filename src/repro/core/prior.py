@@ -4,8 +4,9 @@ After iELAS interpolation the support points have fixed coordinates on a
 regular lattice, so their Delaunay triangulation is known statically: each
 lattice cell splits along its TL-BR diagonal into two triangles.  The prior
 mu(p) at a pixel is the plane through the pixel's containing triangle --
-a closed-form, branch-free, gather-only computation.  This is the payoff of
-the paper's technique: the irregular mesh data structure disappears.
+a closed-form, branch-free computation whose only data movement is a
+static nearest upsample of the grid.  This is the payoff of the paper's
+technique: the irregular mesh data structure disappears.
 """
 from __future__ import annotations
 
@@ -13,8 +14,35 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.params import ElasParams
+
+
+def _upsample(x: jax.Array, idx: np.ndarray, axis: int) -> jax.Array:
+    """``jnp.take(x, idx, axis)`` for a static sorted ``idx`` that steps by
+    0 or 1, as slices, broadcasts and reshapes -- no element gather.
+
+    Consecutive source entries repeated the same number of times form one
+    segment: a slice, broadcast along a new axis and merged into ``axis``.
+    """
+    values, counts = np.unique(idx, return_counts=True)
+    assert np.all(np.diff(values) == 1), "idx must step by 0 or 1"
+    parts = []
+    start = 0
+    for end in range(1, len(values) + 1):
+        if end < len(values) and counts[end] == counts[start]:
+            continue
+        seg = jax.lax.slice_in_dim(x, int(values[start]), int(values[end - 1]) + 1,
+                                   axis=axis)
+        rep = list(seg.shape)
+        rep.insert(axis + 1, int(counts[start]))
+        merged = list(x.shape)
+        merged[axis] = seg.shape[axis] * int(counts[start])
+        parts.append(jnp.broadcast_to(jnp.expand_dims(seg, axis + 1), rep)
+                     .reshape(merged))
+        start = end
+    return jnp.concatenate(parts, axis=axis)
 
 
 @functools.partial(jax.jit, static_argnames=("height", "width", "p"))
@@ -41,10 +69,18 @@ def plane_prior(
     fy = (y - off) / step - iy.astype(jnp.float32)       # may be <0 / >1 at borders
     fx = (x - off) / step - jx.astype(jnp.float32)
 
-    d_tl = support[iy[:, None], jx[None, :]]
-    d_tr = support[iy[:, None], jx[None, :] + 1]
-    d_bl = support[iy[:, None] + 1, jx[None, :]]
-    d_br = support[iy[:, None] + 1, jx[None, :] + 1]
+    # The same cells (iy, jx) from the shapes alone: a static nearest
+    # upsample of the grid reads each pixel's four corners.  fy and fx keep
+    # the device's own floor: with a constant there, the compiler may fuse
+    # the quotient's multiply into the subtraction and change the last bit.
+    cy = np.clip((np.arange(height) - off) // step, 0, gh - 2)
+    cx = np.clip((np.arange(width) - off) // step, 0, gw - 2)
+    top = _upsample(support, cy, 0)                      # (height, GW)
+    bottom = _upsample(support, cy + 1, 0)
+    d_tl = _upsample(top, cx, 1)
+    d_tr = _upsample(top, cx + 1, 1)
+    d_bl = _upsample(bottom, cx, 1)
+    d_br = _upsample(bottom, cx + 1, 1)
 
     fyb = fy[:, None]
     fxb = fx[None, :]
@@ -95,8 +131,10 @@ def right_view_support(
 
     A left node at column u with disparity d corresponds to right column
     u - d.  For each right-view node we take the disparity of the nearest
-    projected left node within one grid pitch; otherwise INVALID.  This is
-    a regular (GW x GW per row) min-reduction -- no scatter.
+    projected left node within one grid pitch (the first such node on a
+    tie); otherwise INVALID.  This is a regular (GW x GW per row)
+    min-reduction -- no scatter, and the value at the argmin is picked by
+    a one-hot max, not a gather.
     """
     from repro.core.support import INVALID
 
@@ -110,7 +148,7 @@ def right_view_support(
     # dist[i, j_right, k_left]
     dist = jnp.abs(proj[:, None, :] - us[None, :, None])
     dist = jnp.where(valid[:, None, :], dist, big)
-    k = jnp.argmin(dist, axis=-1)                                 # (GH, GW)
-    dmin = jnp.take_along_axis(dist, k[..., None], axis=-1)[..., 0]
-    dval = jnp.take_along_axis(support_left, k, axis=-1)
+    dmin = jnp.min(dist, axis=-1)                                 # (GH, GW)
+    pick = jnp.arange(gw) == jnp.argmin(dist, axis=-1)[..., None]
+    dval = jnp.max(jnp.where(pick, support_left[:, None, :], -jnp.inf), axis=-1)
     return jnp.where(dmin <= step, dval, INVALID)

@@ -11,6 +11,7 @@ only one process may load the TPU library, and every test worker imports
 this file.  Keep every such compile in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -101,22 +102,61 @@ def test_support_wave_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_dense_wave_compiles_for_v5e(one_chip):
-    p, h, w = TSUKUBA.params, TSUKUBA.height, TSUKUBA.width
-    desc = jax.ShapeDtypeStruct((WAVE, h, w, 16), jnp.int8, sharding=one_chip)
-    sup = jax.ShapeDtypeStruct((WAVE, *p.grid_shape(h, w)), jnp.float32,
-                               sharding=one_chip)
-    text = _compile(lambda a, b, s: pipeline.ielas_dense_stage_batched(
-        a, b, s, p, backend=BACKEND), desc, desc, sup)
+def _gathers(text: str) -> int:
+    """The number of ``gather`` instructions in a compiled HLO text."""
+    return len(re.findall(r"(?<![\w-])gather\(", text))
+
+
+@pytest.fixture(scope="module")
+def dense_wave(one_chip):
+    """Compiled HLO text of the (warm) dense wave program, once per shape."""
+    texts = {}
+
+    def compiled(config, batch, warm=False) -> str:
+        key = (config, batch, warm)
+        if key not in texts:
+            cfg = CONFIGS[config]
+            p, h, w = cfg.params, cfg.height, cfg.width
+
+            def spec(shape, dtype):
+                return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+            desc = spec((batch, h, w, 16), jnp.int8)
+            if warm:
+                texts[key] = _compile(
+                    lambda a, b, d: pipeline.ielas_warm_dense_stage_batched(
+                        a, b, d, p, backend=BACKEND),
+                    desc, desc, spec((batch, h, w), jnp.float32))
+            else:
+                texts[key] = _compile(
+                    lambda a, b, s: pipeline.ielas_dense_stage_batched(
+                        a, b, s, p, backend=BACKEND),
+                    desc, desc, spec((batch, *p.grid_shape(h, w)), jnp.float32))
+        return texts[key]
+
+    return compiled
+
+
+def test_dense_wave_compiles_for_v5e(dense_wave):
+    text = dense_wave(TSUKUBA.name, WAVE)
     assert "tpu_custom_call" in text
 
 
-def test_warm_dense_wave_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("config,batch", [
+    (TSUKUBA.name, WAVE), (KITTI.name, 1), (KITTI.name, WAVE),
+])
+def test_dense_wave_holds_no_gather(dense_wave, config, batch):
+    """The dense wave's lookups are static slices and selects: an element
+    gather costs the chip about 10 ns an element, a frame's worth of them
+    most of the program's time."""
+    n = _gathers(dense_wave(config, batch))
+    assert n == 0, f"{n} gather instructions in the {config} dense wave at batch {batch}"
+
+
+def test_warm_dense_wave_compiles_for_v5e(dense_wave):
     """The warm scan is plain XLA (no kernel yet); it must still compile
-    for the chip under the ``pallas_tpu`` dispatch."""
-    p, h, w = TSUKUBA.params, TSUKUBA.height, TSUKUBA.width
-    desc = jax.ShapeDtypeStruct((WAVE, h, w, 16), jnp.int8, sharding=one_chip)
-    prior = jax.ShapeDtypeStruct((WAVE, h, w), jnp.float32, sharding=one_chip)
-    text = _compile(lambda a, b, d: pipeline.ielas_warm_dense_stage_batched(
-        a, b, d, p, backend=BACKEND), desc, desc, prior)
+    for the chip under the ``pallas_tpu`` dispatch, and hold no gather."""
+    text = dense_wave(TSUKUBA.name, WAVE, warm=True)
     assert "HloModule" in text
+    n = _gathers(text)
+    assert n == 0, f"{n} gather instructions in the warm dense wave"
