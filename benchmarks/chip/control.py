@@ -7,7 +7,9 @@ In one process, for each seed: one run of the cell as ``run.py`` makes it
 (a window of ``--seconds``), whose check numbers are the program's
 readings; then the control, the plain reference computed in bfloat16
 where the configuration states float32, put in the program's place for
-every frame of that window and judged by the same check.  A sound limit
+every frame of that window (on a warm-start video cell, in the mode,
+cold or warm, the program's frame was judged in) and judged by the same
+check.  A sound limit
 lies above every program reading and below every control reading, so the
 program's runs come out correct and the control's not.  With ``--fault``
 the program runs with one of the faults of ``tests/test_faults.py``
@@ -48,9 +50,9 @@ def main() -> int:
     if args.fault is not None:
         import pytest
 
-        from benchmarks.chip.tests.test_faults import FAULTS
+        from benchmarks.chip.tests.test_faults import FAULTS, WARM_FAULTS
 
-        FAULTS[args.fault][0](pytest.MonkeyPatch())
+        {**FAULTS, **WARM_FAULTS}[args.fault][0](pytest.MonkeyPatch())
     rows = []
     for seed in (int(s) for s in args.seeds.split(",")):
         keep: dict = {}
@@ -60,10 +62,7 @@ def main() -> int:
                "program": {k: v["value"] for k, v in res["check"].items()},
                "program_correct": res["correct"]}
         if args.fault is None:
-            window, refs = keep["window"], keep["references"]
-            ctrl = harness.reference_outputs(cell.config, *keep["pool"], sorted(refs), "bfloat16")
-            numbers = check.compare(check.substituted(window.records, ctrl), refs,
-                                    cell.config["check"], window.strays)
+            numbers = harness.control_numbers(cell, keep)
             for name, (value, limit) in numbers.items():
                 print(f"control check: {name}={value!r} limit={limit!r}", flush=True)
             row["control"] = {k: v for k, (v, _) in numbers.items()}

@@ -4,8 +4,11 @@ Everything a cell is made of is found by name, as data:
 
 * the cell: an entry of ``workloads`` in ``BENCHMARK.json``;
 * its configuration: the ``file`` its ``configs`` entry names, with the
-  plain reference module that file names under ``reference/``;
-* its traffic: ``traffic/<traffic>.json``, read by :mod:`driver`;
+  plain reference module that file names under ``reference/``: a
+  ``disparity(left, right, p, ftype)``, and for warm-start video a
+  ``warm_disparity(left, right, prior, p, warm_band, ftype)``;
+* its traffic: ``traffic/<traffic>.json``, read by :mod:`driver`: pairs or
+  videos, and the service's settings;
 * each end-to-end metric: ``end_to_end/<name>.py``, each per-layer metric:
   ``metrics/<name>.py``, a ``read(ctx)`` that returns a number or None.
 
@@ -16,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import hashlib
 import importlib.util
 import json
 import shutil
@@ -143,18 +147,105 @@ class TraceWindow:
             shutil.rmtree(self.dir, ignore_errors=True)
 
 
-def reference_outputs(config: dict, pool_l, pool_r, indices,
-                      ftype: str = "float32") -> dict:
-    """pool index -> the plain reference's (H, W) float32 disparity, its
-    real-valued arithmetic done in ``ftype``."""
-    import jax.numpy as jnp
-    import numpy as np
+def reference_module(config: dict):
+    return load_module(HERE / "reference" / f"{config['reference']}.py")
 
-    ref_mod = load_module(HERE / "reference" / f"{config['reference']}.py")
-    p = ref_mod.params_from(config)
-    return {i: np.asarray(ref_mod.disparity(jnp.asarray(pool_l[i], jnp.float32),
-                                            jnp.asarray(pool_r[i], jnp.float32), p, ftype))
-            for i in indices}
+
+class References:
+    """The plain reference's answers to the frames of a window, computed on
+    demand, each once: a cold answer by pool index, a warm one by pool
+    index and a digest of its prior.  ``priors``: frame id -> the delivered
+    disparity of the frame its stream submitted just before it, the prior
+    a warm answer to it starts from.  ``warm``: the service's
+    ``(warm_band, rerun_threshold)`` where it runs warm start; without it,
+    or where the reference module has no ``warm_disparity``, no frame has a
+    warm answer."""
+
+    def __init__(self, config: dict, source, priors: dict, warm, ftype: str = "float32"):
+        self.config, self.source, self.warm_settings, self.ftype = config, source, warm, ftype
+        self.mod = reference_module(config)
+        self.p = self.mod.params_from(config)
+        self.priors = priors if warm and hasattr(self.mod, "warm_disparity") else {}
+        self._answers: dict = {}
+        self.count = {"cold": 0, "warm": 0}
+        self.seconds = {"cold": 0.0, "warm": 0.0}
+        self.warm_over_bound = 0
+
+    @staticmethod
+    def priors_of(window) -> dict:
+        return {fid: pred.frame.disparity for fid, pred in window.predecessors().items()
+                if pred is not None and pred.frame is not None and pred.frame.ok}
+
+    def with_ftype(self, ftype: str) -> "References":
+        """The same frames and priors, computed in ``ftype``."""
+        return References(self.config, self.source, self.priors, self.warm_settings, ftype)
+
+    def _answer(self, mode: str, key: tuple, compute):
+        import numpy as np
+
+        if key not in self._answers:
+            t = time.monotonic()
+            self._answers[key] = np.asarray(compute())
+            self.seconds[mode] += time.monotonic() - t
+            self.count[mode] += 1
+        return self._answers[key]
+
+    def _pair(self, r):
+        import jax.numpy as jnp
+
+        return (jnp.asarray(self.source.left[r.pool_index], jnp.float32),
+                jnp.asarray(self.source.right[r.pool_index], jnp.float32))
+
+    def cold(self, r):
+        return self._answer("cold", ("cold", r.pool_index), lambda: self.mod.disparity(
+            *self._pair(r), self.p, self.ftype))
+
+    def warm_answer(self, r):
+        """The warm reference seeded by ``r``'s prior, or None without one."""
+        import jax.numpy as jnp
+
+        prior = self.priors.get(r.frame_id)
+        if prior is None:
+            return None
+        digest = hashlib.blake2b(prior.tobytes(), digest_size=16).digest()
+        return self._answer("warm", ("warm", r.pool_index, digest), lambda: self.mod.warm_disparity(
+            *self._pair(r), jnp.asarray(prior), self.p, self.warm_settings[0], self.ftype))
+
+    def warm(self, r):
+        """The warm answer where the service may deliver it (see
+        :func:`check.warm_stands`), else None."""
+        from benchmarks.chip import check
+
+        want = self.warm_answer(r)
+        if want is None:
+            return None
+        if not check.warm_stands(want, self.priors[r.frame_id], self.p.num_disp,
+                                 self.p.invalid, self.warm_settings[1]):
+            self.warm_over_bound += 1
+            return None
+        return want
+
+    def answers(self, records, modes: dict) -> dict:
+        """frame id -> the answer in the mode ``modes`` gives it."""
+        return {r.frame_id: (self.cold(r) if modes[r.frame_id] == "cold" else self.warm_answer(r))
+                for r in records if r.frame_id in modes}
+
+    def summary(self) -> str:
+        return (" ".join(f"{m}={self.count[m]} {m}_s={self.seconds[m]!r}" for m in self.count)
+                + f" warm_over_bound={self.warm_over_bound}")
+
+
+def control_numbers(cell: Cell, keep: dict, ftype: str = "bfloat16") -> dict:
+    """The check's numbers with the plain reference computed in ``ftype``
+    put in the program's place, each frame in the mode (cold or warm) the
+    program's own check judged it by."""
+    from benchmarks.chip import check
+
+    window, refs = keep["window"], keep["references"]
+    outputs = refs.with_ftype(ftype).answers(window.records, keep["modes"])
+    numbers, _ = check.compare(check.substituted(window.records, outputs), refs.cold,
+                               refs.warm, cell.config["check"], window.strays)
+    return numbers
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
@@ -163,7 +254,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
              keep: dict | None = None) -> dict:
     """One run; returns the result object.  ``device`` None runs wherever
     JAX runs (the harness's own tests) and reports the platform it found.
-    ``keep``, where given, receives the frame pool and reference outputs."""
+    ``keep``, where given, receives the window, the references and the
+    mode each frame was judged in."""
     import jax
 
     from benchmarks.chip import check, driver
@@ -176,8 +268,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     phases = {"start": time.monotonic() - t_process}
 
     t = time.monotonic()
-    pool_l, pool_r = driver.frame_pool(seed, tr["pool"], h, w,
-                                       cfg["scene"]["d_max"], cfg["scene"]["n_objects"])
+    source = driver.frame_source(tr, seed, h, w, cfg["scene"])
     phases["pool"] = time.monotonic() - t
 
     t = time.monotonic()
@@ -187,7 +278,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     phases["compile_and_warmup"] = time.monotonic() - t
 
     t = time.monotonic()
-    driver.prime(svc, pool_l, pool_r, tr)
+    primed = driver.prime(svc, source, tr)
     phases["prime"] = time.monotonic() - t
     setup_s = time.monotonic() - t_process
     log("setup: " + " ".join(f"{k}={v!r}" for k, v in phases.items())
@@ -197,10 +288,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if trace:
         tw = TraceWindow(lead=min(2.0, seconds / 4), length=min(4.0, seconds / 2))
         tw.start()
-    window = driver.closed_loop(svc, pool_l, pool_r, tr, seconds, wait_after_close)
+    window = driver.closed_loop(svc, source, tr, seconds, wait_after_close, primed)
     traced = tw.read() if tw is not None else None
     svc.stop(drain=False)
     stats = svc.stats()
+    warm = (svc.warm_band, svc.rerun_threshold) if svc.warm_start else None
     log(f"service: {stats}")
     peak = memory_peak_bytes(chips) if device is not None else 0
     del svc
@@ -213,15 +305,17 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         f"latency_p95_ms={driver.percentile(lat, 0.95)!r} latency_count={len(lat)}")
 
     t = time.monotonic()
-    used = sorted({r.pool_index for r in window.records})
-    refs = reference_outputs(cfg, pool_l, pool_r, used)
-    log(f"reference: {len(refs)} frames in {time.monotonic() - t!r} s")
+    refs = References(cfg, source, References.priors_of(window), warm)
+    numbers, modes = check.compare(window.records, refs.cold, refs.warm, cfg["check"],
+                                   window.strays)
+    warm_only = sum(1 for m in modes.values() if m == "warm")
+    log(f"reference: {refs.summary()} matched_only_warm={warm_only} "
+        f"in {time.monotonic() - t!r} s")
     if keep is not None:
-        keep.update(pool=(pool_l, pool_r), references=refs, window=window)
-    numbers = check.compare(window.records, refs, cfg["check"], window.strays)
+        keep.update(references=refs, window=window, modes=modes)
 
     ctx = {"cell": cell, "window": window, "setup_s": setup_s, "trace": traced,
-           "device_kind": (device or {}).get("kind"), "log": log}
+           "stats": stats, "device_kind": (device or {}).get("kind"), "log": log}
     metrics = {}
     if trace:
         wanted = cell.per_layer
