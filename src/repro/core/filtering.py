@@ -1,14 +1,32 @@
 """Support-point filtering (Sec. II-A "Filtering").
 
-Two removals, both expressed as static window ops on the dense support grid:
+Two removals, both expressed as static shifts, selects and (for the
+sequential order) a running max on the dense support grid:
 
 * **implausible**: a node must have at least ``incon_min_support`` valid
   neighbours within a ``(2*incon_window+1)^2`` window whose disparity is
   within ``incon_threshold`` -- otherwise it is inconsistent with its
   surroundings and corrupts the coarse representation.
-* **redundant**: a node whose row OR column neighbours within
-  ``redun_max_dist`` on BOTH sides hold (near-)identical disparity adds
-  nothing to the coarse mesh and is removed.
+* **redundant**: a node whose neighbours on BOTH sides of a line, within
+  ``redun_max_dist``, hold (near-)identical disparity adds nothing to the
+  coarse mesh and is removed.  ``ElasParams.redun_order`` picks how:
+
+  - ``"parallel"``: every node is judged against the unfiltered grid, in
+    its column and in its row at once.  On planar scenes nearly every node
+    has a like neighbour on both sides, so a run of like nodes is emptied
+    down to its two ends and the interpolation is left with gaps wider
+    than ``s_delta``.
+  - ``"sequential"``: libelas's ``Elas::removeRedundantSupportPoints``
+    (``src/elas.cpp``): down each column, then along each row of the
+    column pass's output, each node judged in scan order against the grid
+    as already filtered, so a removed node no longer supports the next
+    one and a run of like nodes is thinned to every other node.  At
+    ``redun_max_dist`` 1 that order has a closed form (:func:`_thin_runs`)
+    of a running max and selects, with no gather and no serial loop.
+
+  Two orders exist only because the benchmark's cold configurations pin
+  the parallel one; once they move to the libelas order, the parallel
+  order and the option go.
 """
 from __future__ import annotations
 
@@ -54,7 +72,33 @@ def _redundant_axis(grid: jax.Array, p: ElasParams, axis: int) -> jax.Array:
     return before & after
 
 
+def _thin_runs(grid: jax.Array, p: ElasParams, axis: int) -> jax.Array:
+    """One libelas redundancy pass along ``axis`` at ``redun_max_dist`` 1.
+
+    Two neighbours are *linked* when both are valid and their disparities
+    differ by at most ``redun_threshold``.  Scanning in order and removing
+    in place, a node is removed when it is valid and both its predecessor
+    (as already filtered) and its successor (not yet visited) are linked
+    to it.  A run's first node has no linked predecessor and stays; the
+    second goes if the run goes on; the third then has a removed
+    predecessor and stays; and so on.  So a node goes exactly when it lies
+    at an odd position of its run of linked nodes and its successor is
+    linked.
+    """
+    valid = grid != INVALID
+    dy, dx = (-1, 0) if axis == 0 else (0, -1)
+    succ = _shift2d(grid, dy, dx, INVALID)
+    link_next = valid & (succ != INVALID) & (jnp.abs(succ - grid) <= p.redun_threshold)
+    link_prev = _shift2d(link_next, -dy, -dx, False)
+    idx = jax.lax.broadcasted_iota(jnp.int32, grid.shape, axis)
+    run_start = jax.lax.cummax(jnp.where(link_prev, 0, idx), axis=axis)
+    odd = ((idx - run_start) % 2) == 1
+    return jnp.where(odd & link_next, INVALID, grid)
+
+
 def remove_redundant(grid: jax.Array, p: ElasParams) -> jax.Array:
+    if p.redun_order == "sequential":
+        return _thin_runs(_thin_runs(grid, p, axis=0), p, axis=1)
     valid = grid != INVALID
     redundant = _redundant_axis(grid, p, axis=0) | _redundant_axis(grid, p, axis=1)
     keep = valid & ~redundant

@@ -11,6 +11,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Tuple
 
+REDUN_ORDERS = ("parallel", "sequential")
+
 
 @dataclasses.dataclass(frozen=True)
 class ElasParams:
@@ -30,6 +32,8 @@ class ElasParams:
     incon_min_support: int = 5          # min consistent neighbors to survive
     redun_max_dist: int = 1             # +/- window (grid nodes) for redundancy
     redun_threshold: int = 1            # |d - d_neighbor| <= threshold is "identical"
+    redun_order: str = "parallel"       # "parallel" | "sequential" (libelas);
+                                        # see repro.core.filtering
 
     # --- iELAS support-point interpolation (the paper's technique) ---------------
     s_delta: int = 10                   # search window (grid nodes); 10 nodes = 50 px
@@ -50,6 +54,14 @@ class ElasParams:
     ipol_gap_width: int = 7             # max gap (px) filled by interpolation
     median_radius: int = 1              # 3x3 median
     invalid: float = -1.0               # sentinel for invalid disparity
+
+    def __post_init__(self) -> None:
+        if self.redun_order not in REDUN_ORDERS:
+            raise ValueError(f"redun_order must be one of {REDUN_ORDERS}, "
+                             f"got {self.redun_order!r}")
+        if self.redun_order == "sequential" and self.redun_max_dist != 1:
+            raise ValueError(f"the sequential redundancy filter covers "
+                             f"redun_max_dist=1, got {self.redun_max_dist}")
 
     @property
     def num_disp(self) -> int:

@@ -264,6 +264,9 @@ class ServiceStats:
     warm_refreshes: int = 0        # cold because the streak hit refresh_interval
     warm_reruns: int = 0           # warm frames re-run cold by the post-hoc check
     warm_resets: int = 0           # state dropped (error/shed/out-of-seq/stale)
+    warm_scored: int = 0           # warm frames the post-hoc check scored
+    warm_score_sum: float = 0.0    # sum of their scores over the re-run bound
+                                   # (rerun_threshold * num_disp)
 
 
 # ---------------------------------------------------------------------------
@@ -397,17 +400,20 @@ class FrameProgramCache:
             self._programs[(key, want)] = prog
             return prog
 
-    def warm(self, h: int, w: int) -> WavePrograms:
-        """Pre-compile the program for (h, w) without touching hit/miss
-        counters, and force actual XLA compilation with a dummy wave."""
+    def warm(self, h: int, w: int, batch: Optional[int] = None,
+             cold_only: bool = False) -> WavePrograms:
+        """Pre-compile the program for (h, w) at ``batch`` (default: the
+        bucket's wave width) without touching hit/miss counters, and force
+        actual XLA compilation with a dummy wave.  ``cold_only`` runs only
+        the cold ``support``/``dense`` pair."""
         key = self.bucket_shape(h, w)
-        want = self.batch_for(*key)
+        want = batch if batch is not None else self.batch_for(*key)
         with self._lock:
             prog = self._programs.get((key, want))
             if prog is None:
                 prog = self._build(key, want)
                 self._programs[(key, want)] = prog
-        self._run_dummy(prog)
+        self._run_dummy(prog, cold_only)
         return prog
 
     def calibrate(self, h: int, w: int,
@@ -444,10 +450,12 @@ class FrameProgramCache:
             self.calibrations += 1
         return best_b
 
-    def _run_dummy(self, prog: WavePrograms) -> None:
+    def _run_dummy(self, prog: WavePrograms, cold_only: bool = False) -> None:
         zeros = jnp.zeros((prog.batch, *prog.key), jnp.float32)
         dl, dr, sup = prog.support(zeros, zeros)
         prog.dense(dl, dr, sup).block_until_ready()
+        if cold_only:
+            return
         if prog.dense_degraded is not None:
             prog.dense_degraded(dl, dr, sup).block_until_ready()
         if prog.dense_warm is not None:
@@ -762,6 +770,8 @@ class StereoService:
         self._warm_refreshes = 0
         self._warm_reruns = 0
         self._warm_resets = 0
+        self._warm_scored = 0
+        self._warm_score_sum = 0.0
 
         self._slock = threading.Lock()
         # Ordering lock: guards the in_order reordering state, which is
@@ -934,17 +944,23 @@ class StereoService:
         calibration pass benchmarking candidate wave widths on dummy
         frames; the winner becomes that bucket's wave width and its
         compiled programs are kept, so the hot path still sees zero
-        recompiles after warm-up.
+        recompiles after warm-up.  Under ``warm_start`` it also compiles
+        the batch-1 cold programs the post-hoc re-run uses, so a re-run
+        compiles nothing either.
         """
         if calibrate is None:
             calibrate = self.autobatch
         for h, w in shapes:
+            calibrated = False
             if calibrate and self.batch > 1:
                 before = self._cache.calibrations
                 self._cache.calibrate(h, w)
-                if self._cache.calibrations != before:
-                    continue    # the pass compiled + exercised the winner
-            self._cache.warm(h, w)
+                # the pass compiled + exercised the winner
+                calibrated = self._cache.calibrations != before
+            if not calibrated:
+                self._cache.warm(h, w)
+            if self.warm_start:
+                self._cache.warm(h, w, batch=1, cold_only=True)
 
     def submit(self, frame_id: int, left: np.ndarray, right: np.ndarray,
                stream_id: int = 0,
@@ -1097,7 +1113,8 @@ class StereoService:
         with self._wlock:
             warm = (self._warm_frames, self._cold_frames,
                     self._scene_changes, self._warm_refreshes,
-                    self._warm_reruns, self._warm_resets)
+                    self._warm_reruns, self._warm_resets,
+                    self._warm_scored, self._warm_score_sum)
         dead = set(self._monitor.dead_hosts()) if self._threads else set()
         liveness = tuple(
             (s, s not in dead) for s in _STAGES
@@ -1156,6 +1173,8 @@ class StereoService:
                 warm_refreshes=warm[3],
                 warm_reruns=warm[4],
                 warm_resets=warm[5],
+                warm_scored=warm[6],
+                warm_score_sum=warm[7],
             )
 
     # ------------------------------------------------------- stage plumbing
@@ -1575,10 +1594,13 @@ class StereoService:
         (bitwise equal to the cold search).  Returns ``(out, error)``."""
         score = prior_disagreement(out, req.prior, self.params.num_disp)
         limit = self.rerun_threshold * self.params.num_disp
+        with self._wlock:
+            self._warm_scored += 1
+            self._warm_score_sum += score / limit
+            if score > limit:
+                self._warm_reruns += 1
         if score <= limit:
             return out, None
-        with self._wlock:
-            self._warm_reruns += 1
         try:
             with span("stereo.warm.rerun", request=req.request_id):
                 return self._run_cold_single(req, key), None

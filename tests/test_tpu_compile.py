@@ -10,6 +10,7 @@ The topology is described inside a module fixture, never at import time:
 only one process may load the TPU library, and every test worker imports
 this file.  Keep every such compile in this one file.
 """
+import dataclasses
 import os
 import re
 
@@ -105,6 +106,20 @@ def test_support_wave_compiles_for_v5e(one_chip):
 def _gathers(text: str) -> int:
     """The number of ``gather`` instructions in a compiled HLO text."""
     return len(re.findall(r"(?<![\w-])gather\(", text))
+
+
+@pytest.mark.parametrize("batch", [WAVE, 1])
+def test_libelas_order_support_wave_compiles_for_v5e(one_chip, batch):
+    """The video configuration's support wave (libelas's redundancy order)
+    at KITTI size, as a wave and as the post-hoc re-run's batch 1: it keeps
+    the support search's two descriptor-row gathers and adds none."""
+    p = dataclasses.replace(KITTI.params, redun_order="sequential")
+    img = jax.ShapeDtypeStruct((batch, KITTI.height, KITTI.width), jnp.float32,
+                               sharding=one_chip)
+    text = _compile(lambda a, b: pipeline.ielas_support_stage_batched(
+        a, b, p, backend=BACKEND), img, img)
+    assert "tpu_custom_call" in text
+    assert _gathers(text) == 2
 
 
 @pytest.fixture(scope="module")
